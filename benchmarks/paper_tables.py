@@ -83,7 +83,7 @@ def table3_debuggability():
     step = RT.make_loss_step(model, dcfg)
     specs = RT.model_storage_specs(model, dcfg)
     jit_fn, mesh = RT.wrap_step(model, dcfg, shape, step, (P(), specs))
-    from repro.core.compat import shard_map
+    from jax import shard_map
     eager_fn = shard_map(step, mesh=mesh,
                          in_specs=(specs, RT.batch_specs(model, shape, dcfg)),
                          out_specs=(P(), specs))
@@ -163,7 +163,7 @@ def fig3_vs_gspmd():
     sys.path.insert(0, "examples")
     from quickstart import apply_fn, init_params, VOCAB
 
-    from repro.core.compat import shard_map
+    from jax import shard_map
     from repro.core import simple_fsdp
     from repro.core.dist import make_mesh as _mk
 

@@ -1,8 +1,9 @@
 """Benchmark harness entry point — one function per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV. Distributed behaviour benches run
-on 8 fake CPU devices (set here, in this entry point only — tests and the
-dry-run manage their own device counts).
+Prints ``name,us_per_call,derived`` CSV. A CPU planner tool: distributed
+behaviour benches run on 8 virtual CPU devices (set here, in this entry
+point only — tests and the dry-run manage their own device counts), even
+where a chip is attached. No number it prints is a device measurement.
 
 Run:  PYTHONPATH=src python -m benchmarks.run [table3 table5 ...] [--json]
 
@@ -33,6 +34,7 @@ overlay trace invariant) so the perf trajectory is tracked across PRs.
 import os
 import sys
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=8 "
     + os.environ.get("XLA_FLAGS", "")

@@ -26,11 +26,10 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.sharding import PartitionSpec as P
 
 from repro.core import DistConfig, make_mesh, parallelize, simple_fsdp
-from repro.core.compat import shard_map
-from jax.sharding import PartitionSpec as P
 
 
 def main():

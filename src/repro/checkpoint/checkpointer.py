@@ -50,19 +50,19 @@ class Checkpointer:
     def save(self, step: int, storage, opt_state, model, dcfg: DistConfig,
              extra: dict | None = None):
         metas = model.metas(dcfg)
-        logical = {k: RT.tree_from_storage(storage[k], metas[k], dcfg)
-                   for k in storage}
-        mom = {
-            "m": {k: RT.tree_from_storage(opt_state["m"][k], metas[k], dcfg)
-                  for k in opt_state["m"]},
-            "v": {k: RT.tree_from_storage(opt_state["v"][k], metas[k], dcfg)
-                  for k in opt_state["v"]},
-        }
-        payload = _flatten({"params": logical, **mom})
-        payload["opt_step"] = opt_state["step"]
+
+        def logical(tree):
+            # one group at a time to host memory: the device never holds
+            # more than one group's full copy next to the training state
+            return {k: jax.tree.map(np.asarray, RT.tree_from_storage(
+                tree[k], metas[k], dcfg)) for k in tree}
+
         if self._thread is not None:
             self._thread.join()     # previous async save must land first
-        host = {k: np.asarray(v) for k, v in payload.items()}
+        host = _flatten({"params": logical(storage),
+                         "m": logical(opt_state["m"]),
+                         "v": logical(opt_state["v"])})
+        host["opt_step"] = np.asarray(opt_state["step"])
 
         def _write():
             tmp = os.path.join(self.root, f".tmp_step_{step}")

@@ -15,7 +15,6 @@ Layers (see DESIGN.md):
   api         parallelize() + ParallelPlan — the single entry point
               (simple_fsdp kept as the deprecated bring-your-own-module
               shim)
-  compat      jax version shims (shard_map / make_mesh / keystr)
 """
 
 from repro.core.api import (ParallelPlan, Parallelized, build_metas,
@@ -26,7 +25,6 @@ from repro.core.autowrap import (auto_dp_plan, auto_plan, exposed_comm_time,
 from repro.core.bucketing import (BucketPlan, manual_plan, per_param_plan,
                                   whole_block_plan)
 from repro.core.collectives import gather_group, replicate, replicate_tree
-from repro.core.compat import shard_map
 from repro.core.context import (ring_attention, ring_cost, zigzag_batch,
                                 zigzag_positions)
 from repro.core.dist import DistConfig, make_mesh, single_device_config
@@ -48,7 +46,7 @@ __all__ = [
     "one_f_one_b", "parallelize", "partition_exposure", "per_param_plan",
     "pipe_shift", "pipeline_grads", "pipeline_loss_grads", "plan_parallel",
     "replicate", "replicate_tree", "ring_attention", "ring_cost",
-    "shard_map", "shard_params", "simple_fsdp", "single_device_config",
+    "shard_params", "simple_fsdp", "single_device_config",
     "storage_specs", "to_storage", "unshard_params", "whole_block_plan",
     "zigzag_batch", "zigzag_positions",
 ]

@@ -517,8 +517,7 @@ def build_metas(params_full, cfg: DistConfig, tp_dims: dict[str, int] | None
         )
 
     flat, treedef = jax.tree_util.tree_flatten_with_path(params_full)
-    from repro.core.compat import keystr
-    metas = [one(keystr(p, simple=True, separator="/"), l)
+    metas = [one(jax.tree_util.keystr(p, simple=True, separator="/"), l)
              for p, l in flat]
     return jax.tree_util.tree_unflatten(treedef, metas)
 

@@ -76,10 +76,16 @@ def pack_shards(shards: Sequence[jax.Array]) -> jax.Array:
 
 
 def gather_flat(buf: jax.Array, cfg: DistConfig) -> jax.Array:
-    """One all-gather of the bucket buffer -> (fsdp_size, bucket_len)."""
+    """One all-gather of the bucket buffer -> (fsdp_size, bucket_len).
+
+    Gathers flat (tiled) and reshapes: the TPU compiler lays a non-tiled
+    (fsdp, bucket_len) result out in (fsdp, 128) tiles, and the code it
+    then emits for a large bucket (the embedding's) takes minutes to
+    compile; the flat form takes seconds."""
     if cfg.fsdp_size == 1:
         return buf[None]
-    return lax.all_gather(buf, _fsdp_axes(cfg), tiled=False)
+    return lax.all_gather(buf, _fsdp_axes(cfg), tiled=True).reshape(
+        cfg.fsdp_size, -1)
 
 
 def unpack_gathered(g: jax.Array, metas: Sequence[ParamMeta],
@@ -105,11 +111,16 @@ def pack_grads(grads: Sequence[jax.Array], metas: Sequence[ParamMeta],
 
 
 def reduce_scatter_flat(ct: jax.Array, cfg: DistConfig) -> jax.Array:
-    """One reduce-scatter of the grad bucket -> local (bucket_len,) chunk."""
+    """One reduce-scatter of the grad bucket -> local (bucket_len,) chunk.
+
+    Scatters the flattened buffer (tiled): the same rows reach the same
+    ranks as a scatter over dim 0 of the (fsdp, bucket_len) view, but the
+    TPU compiler spends minutes on the 2-D form of a large bucket (the
+    embedding's) and seconds on the 1-D one."""
     if cfg.fsdp_size == 1:
         return ct[0]
-    return lax.psum_scatter(ct, _fsdp_axes(cfg), scatter_dimension=0,
-                            tiled=False)
+    return lax.psum_scatter(ct.reshape(-1), _fsdp_axes(cfg),
+                            scatter_dimension=0, tiled=True)
 
 
 def split_grad_chunks(flat: jax.Array, metas: Sequence[ParamMeta],

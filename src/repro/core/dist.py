@@ -52,8 +52,10 @@ def precision_codecs(precision: str) -> tuple[str | None, str | None]:
 @dataclasses.dataclass(frozen=True)
 class DistConfig:
     # Mesh ------------------------------------------------------------------
+    # No default: a mesh is sized to the devices a run has (the launchers
+    # derive it from jax.device_count()).
+    mesh_shape: tuple[int, ...]
     mesh_axes: tuple[str, ...] = ("data", "model")
-    mesh_shape: tuple[int, ...] = (16, 16)
     # ZeRO-3 sharding domain for parameters/grads/optimizer states.
     # ('data',)        -> HSDP when a 'pod' axis exists (shard in-pod,
     #                     replicate across pods, grad all-reduce over 'pod')
@@ -271,10 +273,16 @@ class DistConfig:
         return dataclasses.replace(self, **kw)
 
 
-def make_mesh(cfg: DistConfig, devices=None) -> jax.sharding.Mesh:
-    from repro.core import compat
+def make_auto_mesh(shape, axes, devices=None) -> jax.sharding.Mesh:
+    """`jax.make_mesh` with Auto axis types (its default is Explicit); the
+    steps place data with shard_map specs, not with sharding-in-types."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
-    return compat.make_mesh(cfg.mesh_shape, cfg.mesh_axes, devices=devices)
+
+def make_mesh(cfg: DistConfig, devices=None) -> jax.sharding.Mesh:
+    return make_auto_mesh(cfg.mesh_shape, cfg.mesh_axes, devices=devices)
 
 
 def single_device_config(**kw) -> DistConfig:

@@ -9,12 +9,11 @@ Two offloadable stores (chosen by `core/memory/planner.plan_memory`):
     so only the spill over a layer's compute time is exposed (the cost
     model in planner._offload_cost_s).
 
-On TPU runtimes JAX exposes host DRAM as the ``pinned_host`` memory kind
-and these helpers place arrays there for real.  This container's CPU
-backend has no distinct host memory space, so the helpers probe the
-capability once and degrade to identity (the PLAN still records the
-offload decision and the simulator still subtracts the bytes — the modeled
-numbers are the deliverable on this container, DESIGN.md SS2 [changed]).
+Where the backend exposes host DRAM as the ``pinned_host`` memory kind
+(TPU, and the CPU backend of current JAX) these helpers place arrays there
+for real, and a transfer that fails raises. Only a backend without that
+memory kind gets identity, so the plan's offload is then modeled and not
+executed.
 """
 
 from __future__ import annotations
@@ -22,6 +21,7 @@ from __future__ import annotations
 import functools
 
 import jax
+from jax.sharding import SingleDeviceSharding
 
 HOST_MEMORY_KIND = "pinned_host"
 DEVICE_MEMORY_KIND = "device"
@@ -30,28 +30,15 @@ DEVICE_MEMORY_KIND = "device"
 @functools.lru_cache(maxsize=1)
 def host_offload_supported() -> bool:
     """True when the backend exposes a pinned_host memory space."""
-    try:
-        dev = jax.devices()[0]
-        kinds = getattr(dev, "memory_kinds", None)
-        if callable(kinds):
-            return HOST_MEMORY_KIND in kinds()
-        return any(m.kind == HOST_MEMORY_KIND
-                   for m in getattr(dev, "addressable_memories", lambda: [])())
-    except Exception:
-        return False
+    return any(m.kind == HOST_MEMORY_KIND
+               for m in jax.devices()[0].addressable_memories())
 
 
 def _transfer(tree, kind: str):
     if not host_offload_supported():
         return tree
-    try:
-        from jax.sharding import SingleDeviceSharding
-
-        dev = jax.devices()[0]
-        sh = SingleDeviceSharding(dev, memory_kind=kind)
-        return jax.tree.map(lambda x: jax.device_put(x, sh), tree)
-    except Exception:
-        return tree
+    sh = SingleDeviceSharding(jax.devices()[0], memory_kind=kind)
+    return jax.tree.map(lambda x: jax.device_put(x, sh), tree)
 
 
 def to_host(tree):

@@ -152,13 +152,9 @@ def flatten_local(x: jax.Array, meta: ParamMeta, cfg: DistConfig) -> jax.Array:
 # Pytree helpers: params and metas travel as parallel pytrees keyed by path.
 # --------------------------------------------------------------------------
 def named_leaves(tree) -> list[tuple[str, Any]]:
-    from repro.core.compat import keystr
-
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)
-    out = []
-    for path, leaf in flat:
-        out.append((keystr(path, simple=True, separator="/"), leaf))
-    return out
+    return [(jax.tree_util.keystr(path, simple=True, separator="/"), leaf)
+            for path, leaf in flat]
 
 
 def tree_paths(tree) -> list[str]:

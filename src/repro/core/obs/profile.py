@@ -38,8 +38,8 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.core import compat, hw
-from repro.core.dist import precision_codecs
+from repro.core import hw
+from repro.core.dist import make_auto_mesh, precision_codecs
 from repro.core.irgraph import build_nodes
 
 
@@ -92,13 +92,6 @@ class MeasuredProfile:
         return path
 
 
-def _block(tree):
-    """block_until_ready over an arbitrary pytree (old-jax safe)."""
-    jax.tree.map(lambda a: a.block_until_ready()
-                 if hasattr(a, "block_until_ready") else a, tree)
-    return tree
-
-
 def _dcfg1(dcfg):
     """The degenerate 1-device mesh config the harvest scaffolding uses."""
     return dcfg.with_(mesh_axes=("data", "model"), mesh_shape=(1, 1),
@@ -109,11 +102,11 @@ def _dcfg1(dcfg):
 def _time_fn(fn, args, iters: int) -> float:
     """Median wall time of `fn(*args)` with full-readiness fences; one
     warmup call absorbs compile."""
-    _block(fn(*args))
+    jax.block_until_ready(fn(*args))
     walls = []
     for _ in range(max(1, iters)):
         t0 = time.perf_counter()
-        _block(fn(*args))
+        jax.block_until_ready(fn(*args))
         walls.append(time.perf_counter() - t0)
     return statistics.median(walls)
 
@@ -145,8 +138,8 @@ def _profile_segments(model, dcfg, bshape, iters, spans):
         if hasattr(model, "measured_stats"):
             model.measured_stats = saved
 
-    mesh1 = compat.make_mesh((1, 1), ("data", "model"),
-                             devices=jax.devices()[:1])
+    mesh1 = make_auto_mesh((1, 1), ("data", "model"),
+                           devices=jax.devices()[:1])
     metas = model.block_metas(dcfg1)
     B, S = bshape
     consts = model.consts(S, dcfg1)
@@ -181,8 +174,8 @@ def _profile_segments(model, dcfg, bshape, iters, spans):
         def seg_fn(p, st, s=s):
             return seg_fns[s](p, consts, st)
 
-        wrapped = compat.shard_map(seg_fn, mesh=mesh1, in_specs=(P(), P()),
-                                   out_specs=P(), check_vma=False)
+        wrapped = jax.shard_map(seg_fn, mesh=mesh1, in_specs=(P(), P()),
+                                out_specs=P(), check_vma=False)
         jfn = jax.jit(wrapped)
         dt = _time_fn(jfn, (masked, state), iters)
         state = jfn(masked, state)
@@ -222,7 +215,7 @@ def _profile_collectives(model, plan, iters, spans,
     if metas is None:
         return {}
     nodes = {n.name: n for n in build_nodes(metas, dcfg, None)}
-    mesh = compat.make_mesh(dcfg.mesh_shape, dcfg.mesh_axes)
+    mesh = make_auto_mesh(dcfg.mesh_shape, dcfg.mesh_axes)
     fsdp = dcfg.fsdp_size
     itemsize = jnp.dtype(dcfg.param_dtype).itemsize
     axes = dcfg.fsdp_axes
@@ -235,10 +228,10 @@ def _profile_collectives(model, plan, iters, spans,
     def rs_fn(ct):
         return C.reduce_scatter_flat(ct, dcfg)
 
-    ag_w = jax.jit(compat.shard_map(ag_fn, mesh=mesh, in_specs=(P(axes),),
-                                    out_specs=P(), check_vma=False))
-    rs_w = jax.jit(compat.shard_map(rs_fn, mesh=mesh, in_specs=(P(),),
-                                    out_specs=P(axes), check_vma=False))
+    ag_w = jax.jit(jax.shard_map(ag_fn, mesh=mesh, in_specs=(P(axes),),
+                                 out_specs=P(), check_vma=False))
+    rs_w = jax.jit(jax.shard_map(rs_fn, mesh=mesh, in_specs=(P(),),
+                                 out_specs=P(axes), check_vma=False))
 
     rows = []
     groups = plan.bucket_plans[key].groups
@@ -345,12 +338,12 @@ def _profile_wall(model, plan, shape, steps, spans):
         from repro.core.context import zigzag_batch
         batch = zigzag_batch(batch, dcfg)
     storage, opt, m = step_fn(storage, opt, batch)       # compile + warmup
-    _block((storage, m))
+    jax.block_until_ready((storage, m))
     walls = []
     for k in range(max(1, steps)):
         t0 = time.perf_counter()
         storage, opt, m = step_fn(storage, opt, batch)
-        _block((storage, m))
+        jax.block_until_ready((storage, m))
         dt = time.perf_counter() - t0
         walls.append(dt)
         spans.append({"name": f"step[{k}]", "cat": "wall", "dur_s": dt})
@@ -413,13 +406,9 @@ def profile_step(model, plan, shape, steps: int = 2,
 
     rank_step_s = {str(jax.process_index()): wall_step_s}
     if jax.process_count() > 1:       # per-rank rows under a real multi-
-        try:                          # process launch (dist harness style)
-            from jax.experimental import multihost_utils
-            walls = multihost_utils.process_allgather(
-                jnp.asarray(wall_step_s))
-            rank_step_s = {str(r): float(w) for r, w in enumerate(walls)}
-        except Exception:
-            pass
+        from jax.experimental import multihost_utils  # process launch
+        walls = multihost_utils.process_allgather(jnp.asarray(wall_step_s))
+        rank_step_s = {str(r): float(w) for r, w in enumerate(walls)}
 
     profile = MeasuredProfile(
         meta={"plan": plan.describe(),

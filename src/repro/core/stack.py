@@ -71,7 +71,6 @@ import numpy as np
 from jax import lax
 
 from repro.core import collectives as coll
-from repro.core import compat
 from repro.core.bucketing import (BucketPlan, assign_segments, plan_for,
                                   split_plan_at_segments)
 from repro.core.dist import DistConfig
@@ -298,13 +297,13 @@ def _prefetch_stack(block_fn, metas_tree, cfg, plan, stacked, consts, x,
             def tok(vma):
                 key = frozenset(vma)
                 if key not in tokens:
-                    extra = tuple(a for a in compat.vma_of(base)
+                    extra = tuple(a for a in jax.typeof(base).vma
                                   if a not in key)
                     tokens[key] = lax.psum(base, extra) if extra else base
                 return tokens[key]
 
             shards = [
-                lax.optimization_barrier((sh, tok(compat.vma_of(sh))))[0]
+                lax.optimization_barrier((sh, tok(jax.typeof(sh).vma)))[0]
                 for sh in shards
             ]
         full: list = [None] * len(shards)

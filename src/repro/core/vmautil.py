@@ -12,21 +12,15 @@ import jax
 from jax import lax
 
 
-def _vma(x) -> frozenset:
-    from repro.core.compat import vma_of
-
-    return vma_of(x)
-
-
 def vary_to(x, axes):
-    need = tuple(a for a in axes if a not in _vma(x))
+    need = tuple(a for a in axes if a not in jax.typeof(x).vma)
     return lax.pcast(x, need, to="varying") if need else x
 
 
 def tree_vma_union(tree) -> frozenset:
     out: frozenset = frozenset()
     for leaf in jax.tree.leaves(tree):
-        out |= _vma(leaf)
+        out |= jax.typeof(leaf).vma
     return out
 
 
@@ -34,3 +28,11 @@ def vary_like(tree, ref_tree):
     """Upcast every leaf of `tree` to the vma union of `ref_tree`."""
     axes = tuple(sorted(tree_vma_union(ref_tree)))
     return jax.tree.map(lambda v: vary_to(v, axes), tree)
+
+
+def pallas_operands(*xs):
+    """Cast a `pallas_call`'s operands to one common vma and return them
+    with it: inside shard_map every `out_shape` must carry the vma of the
+    values it is computed from (`ShapeDtypeStruct(..., vma=vma)`)."""
+    xs = vary_like(xs, xs)
+    return (*xs, tree_vma_union(xs))

@@ -28,10 +28,11 @@ def adamw_update_pallas(p, g, m, v, *, lr, b1, b2, eps, wd, t,
             else x.reshape(-1)
         return jnp.pad(f, (0, pad)) if pad else f
 
-    lr_a = jnp.asarray([lr], jnp.float32)
-    t_a = jnp.asarray([t], jnp.float32).reshape(1)
+    t = jnp.asarray(t, jnp.float32)
+    scalars = jnp.stack([jnp.asarray(lr, jnp.float32),
+                         1 - b1 ** t, 1 - b2 ** t])
     po, mo, vo = K.adamw_flat(flat(p), flat(g).astype(p.dtype),
-                              flat(m), flat(v), lr_a, t_a,
+                              flat(m), flat(v), scalars,
                               b1=b1, b2=b2, eps=eps, wd=wd,
                               interpret=interpret)
     unflat = lambda x: x[:n].reshape(shape)

@@ -18,10 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
-
 
 ROW_BLOCK = 8
 V_BLOCK = 2048
@@ -77,7 +73,7 @@ def xent_fwd(logits, targets, vocab: int | None = None,
         out_specs=[pl.BlockSpec((ROW_BLOCK,), lambda i, j: (i,)),
                    pl.BlockSpec((ROW_BLOCK,), lambda i, j: (i,))],
         scratch_shapes=[pltpu.VMEM((ROW_BLOCK,), jnp.float32)] * 3,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(logits, targets)

@@ -21,10 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
-
 
 Q_BLOCK = 128
 KV_BLOCK = 128
@@ -102,7 +98,7 @@ def flash_fwd(q, k, v, *, causal=True, window=None, softcap=None,
             pltpu.VMEM((Q_BLOCK,), jnp.float32),      # running max
             pltpu.VMEM((Q_BLOCK,), jnp.float32),      # running sum
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.vmautil import pallas_operands
 from repro.kernels.quant import ref
 
 ROW_BLOCK = 8
@@ -51,11 +52,12 @@ def quant_fwd(x2d: jax.Array, seed: jax.Array, codec: str,
     assert d == QCHUNK and m % ROW_BLOCK == 0, "ops.py pads"
     kernel = functools.partial(_quant_kernel, codec=codec,
                                stochastic=stochastic)
+    x2d, seed, vma = pallas_operands(x2d, seed.reshape(1, 1))
     return pl.pallas_call(
         kernel,
         out_shape=[
-            jax.ShapeDtypeStruct((m, d), ref.WIRE_DTYPE[codec]),
-            jax.ShapeDtypeStruct((m, 1), jnp.float32),
+            jax.ShapeDtypeStruct((m, d), ref.WIRE_DTYPE[codec], vma=vma),
+            jax.ShapeDtypeStruct((m, 1), jnp.float32, vma=vma),
         ],
         grid=(m // ROW_BLOCK,),
         in_specs=[
@@ -67,7 +69,8 @@ def quant_fwd(x2d: jax.Array, seed: jax.Array, codec: str,
             pl.BlockSpec((ROW_BLOCK, 1), lambda i: (i, 0)),
         ],
         interpret=interpret,
-    )(x2d, seed.reshape(1, 1))
+        name="quant",
+    )(x2d, seed)
 
 
 def _dequant_kernel(q_ref, s_ref, o_ref):
@@ -80,9 +83,10 @@ def dequant_fwd(q: jax.Array, scales: jax.Array,
     """Inverse pass: wire values + per-chunk scales -> f32 chunk view."""
     m, d = q.shape
     assert d == QCHUNK and m % ROW_BLOCK == 0, "ops.py pads"
+    q, scales, vma = pallas_operands(q, scales)
     return pl.pallas_call(
         _dequant_kernel,
-        out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32, vma=vma),
         grid=(m // ROW_BLOCK,),
         in_specs=[
             pl.BlockSpec((ROW_BLOCK, d), lambda i: (i, 0)),
@@ -90,4 +94,5 @@ def dequant_fwd(q: jax.Array, scales: jax.Array,
         ],
         out_specs=pl.BlockSpec((ROW_BLOCK, d), lambda i: (i, 0)),
         interpret=interpret,
+        name="dequant",
     )(q, scales)

@@ -8,8 +8,8 @@ for LANE-aligned buckets — the figure the planner prices.
 Encode is round-to-nearest for params (forward all-gather: deterministic,
 bit-identical across ranks) and stochastic for grads (reduce-scatter:
 unbiased, the condition Markov et al.'s EF convergence analysis needs).
-Stochastic rounding is hand-rolled — jax 0.4 has no pltpu.stochastic_round:
-fp8 adds a 20-bit uniform dither below e4m3's 3 retained mantissa bits in
+Stochastic rounding is hand-rolled, so the Pallas kernel and this reference
+share it bit for bit: fp8 adds a 20-bit uniform dither below e4m3's 3 retained mantissa bits in
 the f32 bit pattern and truncates; int8 uses floor(y + u).  The dither is
 an integer hash of (seed + flat index); the seed is the wraparound u32 sum
 of the buffer's own bits — data-dependent yet trace-safe, so no PRNG key
@@ -57,7 +57,9 @@ def sr_fp8(y: jax.Array, h: jax.Array) -> jax.Array:
 
 
 def sr_int8(y: jax.Array, h: jax.Array) -> jax.Array:
-    u = (h >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
+    # (h >> 8) fits in 24 bits: the int32 hop is exact, and Mosaic has no
+    # uint32 -> float32 cast
+    u = (h >> 8).astype(jnp.int32).astype(jnp.float32) * (1.0 / (1 << 24))
     return jnp.clip(jnp.floor(y + u), -127.0, 127.0).astype(jnp.int8)
 
 

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.vmautil import pallas_operands
+
 ROW_BLOCK = 8
 
 
@@ -32,9 +34,10 @@ def rmsnorm_fwd(x2d: jax.Array, w: jax.Array, eps: float,
     assert rows % ROW_BLOCK == 0, "ops.py pads rows"
     kernel = functools.partial(_rmsnorm_kernel, eps=eps,
                                unit_offset=unit_offset)
+    x2d, w, vma = pallas_operands(x2d, w)
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((rows, d), x2d.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows, d), x2d.dtype, vma=vma),
         grid=(rows // ROW_BLOCK,),
         in_specs=[
             pl.BlockSpec((ROW_BLOCK, d), lambda i: (i, 0)),
@@ -42,4 +45,5 @@ def rmsnorm_fwd(x2d: jax.Array, w: jax.Array, eps: float,
         ],
         out_specs=pl.BlockSpec((ROW_BLOCK, d), lambda i: (i, 0)),
         interpret=interpret,
+        name="rmsnorm",
     )(x2d, w)

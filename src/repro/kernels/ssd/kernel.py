@@ -19,10 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
-
 
 CHUNK = 128
 
@@ -81,7 +77,7 @@ def ssd_fwd(A, x, dt, Bm, Cm, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((1, 1, Lc, P), lambda b, c: (b, c, 0, 0)),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(A, x, dt, Bm, Cm)

@@ -3,7 +3,10 @@ import os
 if __name__ == "__main__":
     # entry-point only: must land before jax initializes.  Library imports
     # (tests harvesting BlockStats/MemoryStats in-process) must NOT mutate
-    # the environment — os.environ leaks into every later subprocess.
+    # the environment — os.environ leaks into every later subprocess.  A
+    # CPU planner tool: it runs on 512 virtual CPU devices even where a
+    # chip is attached.
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (
         "--xla_force_host_platform_device_count=512 "
         + os.environ.get("XLA_FLAGS", "")
@@ -47,12 +50,12 @@ import traceback
 
 import jax
 import jax.numpy as jnp
-from repro.core.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat, hw
-from repro.core.dist import DistConfig
+from repro.core import hw
+from repro.core.dist import DistConfig, make_auto_mesh
 from repro.core.irgraph import BlockStats
 from repro.core.meta import ParamMeta
 from repro.launch.mesh import make_production_mesh, production_dcfg
@@ -196,8 +199,8 @@ def _harvest_setup(model, dcfg: DistConfig, batch_shape):
         if hasattr(model, "measured_stats"):
             model.measured_stats = saved
 
-    mesh1 = compat.make_mesh((1, 1), ("data", "model"),
-                             devices=jax.devices()[:1])
+    mesh1 = make_auto_mesh((1, 1), ("data", "model"),
+                           devices=jax.devices()[:1])
     metas = model.block_metas(dcfg1)
     B, S = batch_shape
     consts = model.consts(S, dcfg1)
@@ -723,8 +726,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
         assert _m.prod(mesh_shape) == (512 if multi_pod else 256)
         assert context_degree == 1, "--mesh-shape and --cp are exclusive"
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-        from repro.core import compat
-        mesh = compat.make_mesh(mesh_shape, axes)
+        mesh = make_auto_mesh(mesh_shape, axes)
         dcfg = production_dcfg(multi_pod=multi_pod, zero3_global=zero3) \
             .with_(mesh_shape=mesh_shape)
     else:

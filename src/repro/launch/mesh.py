@@ -21,8 +21,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.core import compat
-from repro.core.dist import DistConfig
+from repro.core.dist import DistConfig, make_auto_mesh
 
 
 def _production_layout(multi_pod: bool, pipeline_stages: int,
@@ -51,7 +50,7 @@ def make_production_mesh(*, multi_pod: bool = False,
                          context_degree: int = 1):
     shape, axes = _production_layout(multi_pod, pipeline_stages,
                                      context_degree)
-    return compat.make_mesh(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def production_dcfg(*, multi_pod: bool = False, zero3_global: bool = False,

@@ -1,11 +1,18 @@
 """Serving launcher CLI: prefill a synthetic batch, decode N tokens.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen3_1_7b --smoke \
-      --devices 8 --mesh 2,4 --gen 16
+On an accelerator the mesh spans the real devices (default: every device
+on 'model'); under JAX_PLATFORMS=cpu, --devices N (or the size of --mesh)
+virtual CPU devices stand in for chips.
+
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3_1_7b --gen 16
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve \
+      --arch qwen3_1_7b --smoke --mesh 2,4 --gen 16
 """
 
 import argparse
-import os
+import math
+
+from repro.launch import jaxenv
 
 
 def main():
@@ -15,17 +22,23 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=24)
     ap.add_argument("--gen", type=int, default=8)
-    ap.add_argument("--devices", type=int, default=8)
-    ap.add_argument("--mesh", default="2,4")
+    ap.add_argument("--devices", type=int, default=0,
+                    help="virtual CPU device count under JAX_PLATFORMS=cpu "
+                         "(0 = sized to --mesh); not allowed elsewhere")
+    ap.add_argument("--mesh", default=None,
+                    help="'data,model' (default: every device on 'model')")
     ap.add_argument("--int8-kv", action="store_true")
     ap.add_argument("--metrics-jsonl", default=None,
                     help="append a metrics-registry snapshot (compile + "
                          "steady prefill/decode timings) here (core/obs)")
     args = ap.parse_args()
 
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={args.devices} "
-        + os.environ.get("XLA_FLAGS", ""))
+    if args.devices and not jaxenv.on_cpu():
+        raise SystemExit("--devices needs JAX_PLATFORMS=cpu")
+    mesh_shape = tuple(int(x) for x in args.mesh.split(",")) \
+        if args.mesh else None
+    jaxenv.set_cpu_device_count(
+        args.devices or (math.prod(mesh_shape) if mesh_shape else 0))
 
     import time
 
@@ -39,7 +52,8 @@ def main():
     from repro.models.registry import get_arch
     from repro.train import serve as SV
 
-    mesh_shape = tuple(int(x) for x in args.mesh.split(","))
+    print(f"compile cache: {jaxenv.init_compile_cache()}")
+    mesh_shape = mesh_shape or (1, jax.device_count())
     dcfg = DistConfig(mesh_axes=("data", "model"), mesh_shape=mesh_shape,
                       param_dtype=jnp.float32, reduce_dtype=jnp.float32,
                       kv_cache_int8=args.int8_kv)

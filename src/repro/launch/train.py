@@ -1,21 +1,34 @@
 """Training launcher CLI.
 
-On real hardware this runs under one process per host with
-jax.distributed.initialize(); on this container it drives the same code on
-fake CPU devices. Selects any assigned architecture; mesh axes are DERIVED
-from the flags (2 entries -> (data, model), 3 -> (pod, data, model); --pp>1
-prepends an outermost 'pipe' axis), and the single Trainer routes through
-`core/api.parallelize` — pp x dp x tp is a config flip.
+Two run modes, one code path:
 
-  PYTHONPATH=src python -m repro.launch.train --arch qwen3_1_7b --smoke \
-      --steps 50 --mesh 4,2
-  PYTHONPATH=src python -m repro.launch.train --arch qwen3_1_7b --smoke \
-      --steps 50 --mesh 2,2 --pp 2 --pp-schedule zb --pp-microbatches 4
+  * on an accelerator (JAX's default platform), the mesh spans the real
+    devices: without --mesh it is (jax.device_count(), 1) over
+    (data, model);
+  * on the CPU (JAX_PLATFORMS=cpu), --devices N (or the size of --mesh)
+    virtual CPU devices stand in for chips.
+
+Selects any assigned architecture; mesh axes are DERIVED from the flags (2
+entries -> (data, model), 3 -> (pod, data, model); --pp>1 prepends an
+outermost 'pipe' axis), and the single Trainer routes through
+`core/api.parallelize` — pp x dp x tp is a config flip. Each run starts
+from a fresh checkpoint directory unless --ckpt-dir names one to resume.
+
+  PYTHONPATH=src python -m repro.launch.train --arch qwen3_1_7b --layers 7 \
+      --steps 3 --seq 4096 --batch 1
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.train \
+      --arch qwen3_1_7b --smoke --steps 50 --mesh 4,2
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.train \
+      --arch qwen3_1_7b --smoke --steps 50 --mesh 2,2 --pp 2 \
+      --pp-schedule zb --pp-microbatches 4
 """
 
 import argparse
+import dataclasses
 import math
-import os
+import tempfile
+
+from repro.launch import jaxenv
 
 
 def mesh_from_flags(mesh: str, pp: int, cp: int = 1) \
@@ -49,13 +62,18 @@ def main():
     ap.add_argument("--arch", default="qwen3_1_7b")
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced config (CPU-runnable)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the stack to this many layers (0 = the "
+                         "config's): one chip's stage of a deeper model")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--devices", type=int, default=0,
-                    help="fake CPU device count (0 = sized to the mesh)")
-    ap.add_argument("--mesh", default="4,2",
-                    help="non-pipe mesh: 'data,model' or 'pod,data,model'")
+                    help="virtual CPU device count under JAX_PLATFORMS=cpu "
+                         "(0 = sized to --mesh); not allowed elsewhere")
+    ap.add_argument("--mesh", default=None,
+                    help="non-pipe mesh: 'data,model' or 'pod,data,model' "
+                         "(default: every device on 'data')")
     ap.add_argument("--pp", type=int, default=1,
                     help="pipeline stages; >1 adds an outermost 'pipe' axis")
     ap.add_argument("--pp-schedule", default="auto",
@@ -89,7 +107,10 @@ def main():
                          "per bucket from the full lattice")
     ap.add_argument("--no-reorder", action="store_true")
     ap.add_argument("--grad-compression", action="store_true")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_train")
+    ap.add_argument("--ckpt-dir", default=None,
+                    help="checkpoint directory; a run resumes from the "
+                         "newest checkpoint in it (default: a fresh "
+                         "temporary directory)")
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--metrics-jsonl", default=None,
                     help="append a metrics-registry snapshot (step time, "
@@ -116,23 +137,33 @@ def main():
                          "(default: log the delta only)")
     args = ap.parse_args()
 
-    mesh_shape, mesh_axes = mesh_from_flags(args.mesh, args.pp, args.cp)
-    devices = args.devices or math.prod(mesh_shape)
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={devices} "
-        + os.environ.get("XLA_FLAGS", ""))
+    if args.devices and not jaxenv.on_cpu():
+        raise SystemExit("--devices needs JAX_PLATFORMS=cpu")
+    if args.mesh:
+        mesh_shape, mesh_axes = mesh_from_flags(args.mesh, args.pp, args.cp)
+        jaxenv.set_cpu_device_count(args.devices or math.prod(mesh_shape))
+    else:
+        jaxenv.set_cpu_device_count(args.devices)
 
     import logging
 
+    import jax
     import jax.numpy as jnp
 
     from repro.core.dist import DistConfig
     from repro.models.common import ShapeConfig
-    from repro.models.registry import get_arch, get_arch_for_pp
+    from repro.models.registry import build_model, get_arch, get_arch_for_pp
     from repro.optim.adamw import AdamWConfig
     from repro.train.trainer import Trainer, TrainerConfig
 
     logging.basicConfig(level=logging.INFO)
+    print(f"compile cache: {jaxenv.init_compile_cache()}")
+    if not args.mesh:
+        data = max(1, jax.device_count() // (args.pp * args.cp))
+        mesh_shape, mesh_axes = mesh_from_flags(f"{data},1", args.pp, args.cp)
+    ckpt_dir = args.ckpt_dir or tempfile.mkdtemp(prefix="repro_train_")
+    print(f"devices: {jax.device_count()} x {jax.devices()[0].device_kind}; "
+          f"checkpoints: {ckpt_dir}")
     dcfg = DistConfig(
         mesh_axes=mesh_axes, mesh_shape=mesh_shape,
         pp_axis="pipe" if args.pp > 1 else None,
@@ -154,9 +185,12 @@ def main():
                                      smoke=args.smoke)
     else:
         cfg, model = get_arch(args.arch, smoke=args.smoke)
+    if args.layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
+        model = build_model(cfg)
     shape = ShapeConfig("train", args.seq, args.batch, "train")
     tcfg = TrainerConfig(total_steps=args.steps, ckpt_every=args.steps,
-                         log_every=5, warmup=10, ckpt_dir=args.ckpt_dir,
+                         log_every=5, warmup=10, ckpt_dir=ckpt_dir,
                          metrics_jsonl=args.metrics_jsonl,
                          replan_threshold=args.replan_threshold,
                          replan_patience=args.replan_patience,

@@ -29,6 +29,7 @@ from jax import lax
 
 from repro.core.dist import DistConfig
 from repro.core.meta import ParamMeta
+from repro.core.vmautil import vary_like
 from repro.models.common import ArchConfig
 
 
@@ -203,10 +204,11 @@ def attention_chunked(q, k, v, *, causal=True, window=None, softcap=None,
                     "kgqt,tkh->kgqh", p, vj.astype(jnp.float32))
                 return (acc_new, m_new, l_new), None
 
-            acc0 = jnp.zeros((Kh, group, q_chunk, hd), jnp.float32)
-            m0 = jnp.full((Kh, group, q_chunk), -jnp.inf)
-            l0 = jnp.zeros((Kh, group, q_chunk))
-            (acc, m, l), _ = lax.scan(kv_step, (acc0, m0, l0),
+            carry0 = vary_like(
+                (jnp.zeros((Kh, group, q_chunk, hd), jnp.float32),
+                 jnp.full((Kh, group, q_chunk), -jnp.inf),
+                 jnp.zeros((Kh, group, q_chunk))), (qi, kb, vb))
+            (acc, m, l), _ = lax.scan(kv_step, carry0,
                                       (kb, vb, jnp.arange(nk)))
             out = acc / jnp.maximum(l[..., None], 1e-30)
             # (Kh, g, qc, hd) -> (qc, Kh, g, hd)

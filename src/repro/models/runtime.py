@@ -8,9 +8,7 @@ the same way.
 from __future__ import annotations
 
 import jax
-from jax import lax
-
-from repro.core.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.api import shard_params, unshard_params

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from repro.core.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.dist import DistConfig, make_mesh

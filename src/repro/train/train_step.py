@@ -28,9 +28,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from repro.core.compat import shard_map
+from jax import lax, shard_map
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.dist import DistConfig, make_mesh
@@ -124,6 +123,17 @@ def make_train_step(model, dcfg: DistConfig, ocfg: AdamWConfig,
         return new_p, new_opt, metrics
 
     return step_local
+
+
+def train_state_shardings(par):
+    """NamedShardings of (storage, opt_state) as the train step of `par`
+    (a `core.api.Parallelized`) lays them out. State placed so traces the
+    same program as the step's own outputs do; host-initialised state
+    would trace, and compile, a second one."""
+    pspecs = par.storage_specs
+    return jax.tree.map(lambda s: NamedSharding(par.mesh, s),
+                        (pspecs, _opt_specs(pspecs, par.dcfg)),
+                        is_leaf=lambda x: isinstance(x, P))
 
 
 def wrap_train_step(model, dcfg: DistConfig, shape, ocfg: AdamWConfig,

@@ -32,18 +32,20 @@ from repro.data.pipeline import DataConfig, SyntheticC4, adapt_batch
 from repro.ft.failures import (FailureSource, StepTimer, StragglerMonitor)
 from repro.models.common import ShapeConfig
 from repro.optim.adamw import AdamWConfig
-from repro.train.train_step import default_schedule, init_train_state
+from repro.train.train_step import (default_schedule, init_train_state,
+                                    train_state_shardings)
 
 log = logging.getLogger("repro.trainer")
 
 
 @dataclasses.dataclass
 class TrainerConfig:
+    ckpt_dir: str                     # a run resumes from the newest
+                                      # checkpoint here: give each run its own
     total_steps: int = 100
     ckpt_every: int = 50
     log_every: int = 10
     warmup: int = 10
-    ckpt_dir: str = "/tmp/repro_ckpt"
     async_ckpt: bool = False
     max_restarts: int = 3
     stop_after: int | None = None     # pause the job early (schedule horizon
@@ -91,6 +93,10 @@ class Trainer:
         # harvested MeasuredProfile, and one delta record per replan
         self._drift_streak = 0
         self._replan_pending = False
+        # the first step of each step_fn compiles it: its wall is no
+        # steady-state measurement for a replan to calibrate against
+        self._fresh_fn = True
+        self._compile_steps: set[int] = set()
         self.profile = None
         self.replans: list[dict] = []
         # observability: one registry + drift monitor per trainer; the
@@ -99,15 +105,8 @@ class Trainer:
         self.registry = registry if registry is not None \
             else MetricsRegistry()
         self.drift = DriftMonitor(self.registry)
-        try:
-            self._modeled_step_s = modeled_step_time(model, self.plan,
-                                                     shape)
-        except Exception:
-            self._modeled_step_s = None
-        try:
-            self._wire = step_wire_metrics(model, self.plan)
-        except Exception:
-            self._wire = None
+        self._modeled_step_s = modeled_step_time(model, self.plan, shape)
+        self._wire = step_wire_metrics(model, self.plan)
         if self.plan.memory is not None:
             log.info("plan: %s", self.plan.describe())
             for b in self.plan.memory.breakdown:
@@ -150,22 +149,30 @@ class Trainer:
 
     # ------------------------------------------------------------------ --
     def _init_or_restore(self, key):
+        """Train state on the shardings the step lays it out in (state
+        placed otherwise traces, and compiles, a second step program)."""
+        shardings = train_state_shardings(self.par)
         latest = self.ckpt.latest_step()
-        if latest is not None:
-            storage, opt_state, _ = self.ckpt.restore(latest, self.model,
-                                                      self.dcfg)
-            # checkpoints hold the plain layout; stage it for this plan
-            storage = self.par.stage_storage(storage)
-            if self.plan.pipelined:
-                from repro.models import staging
-                opt_state = staging.stage_opt_state(
-                    opt_state, self.plan.stage, self.dcfg,
-                    self.par.pipe_sharded)
-            log.info("restored step %d", latest)
-            return storage, opt_state, latest
-        storage, opt_state = init_train_state(self.model, self.dcfg, key,
-                                              plan=self.plan)
-        return storage, opt_state, 0
+        if latest is None:
+            # made where it lives: each device builds only its own shards,
+            # and no device ever holds the whole model
+            storage, opt_state = jax.jit(
+                lambda k: init_train_state(self.model, self.dcfg, k,
+                                           plan=self.plan),
+                out_shardings=shardings)(key)
+            return storage, opt_state, 0
+        storage, opt_state, _ = self.ckpt.restore(latest, self.model,
+                                                  self.dcfg)
+        # checkpoints hold the plain layout; stage it for this plan
+        storage = self.par.stage_storage(storage)
+        if self.plan.pipelined:
+            from repro.models import staging
+            opt_state = staging.stage_opt_state(
+                opt_state, self.plan.stage, self.dcfg,
+                self.par.pipe_sharded)
+        log.info("restored step %d", latest)
+        storage, opt_state = jax.device_put((storage, opt_state), shardings)
+        return storage, opt_state, latest
 
     def _save(self, step, storage, opt_state):
         if self.plan.pipelined:
@@ -197,9 +204,8 @@ class Trainer:
             self.shape.seq_len * self.shape.global_batch / max(1e-9, dt))
         r.gauge("train/grad_norm").set(float(metrics["grad_norm"]))
         r.gauge("train/loss").set(float(metrics["loss"]))
-        if self._wire is not None:
-            for prec, nbytes in self._wire["by_precision"].items():
-                r.counter(f"train/wire_bytes/{prec}").inc(nbytes)
+        for prec, nbytes in self._wire["by_precision"].items():
+            r.counter(f"train/wire_bytes/{prec}").inc(nbytes)
         if self._modeled_step_s is not None:
             rel = self.drift.record("step_time", self._modeled_step_s, dt,
                                     step=step)
@@ -224,7 +230,8 @@ class Trainer:
 
         self._replan_pending = False
         self._drift_streak = 0
-        rows = self.drift.records.get("step_time", [])
+        rows = [r for r in self.drift.records.get("step_time", [])
+                if r["step"] not in self._compile_steps]
         recent = [r["measured"]
                   for r in rows[-max(1, self.tcfg.replan_patience):]]
         wall = sum(recent) / len(recent) if recent else None
@@ -262,6 +269,7 @@ class Trainer:
         self.plan = self.par.plan
         self.mesh = self.par.mesh
         self.step_fn = self.par.train_step(self.ocfg, sched)
+        self._fresh_fn = True
         try:
             self._modeled_step_s = calibrated_step_time(
                 self.model, self.plan, self.shape, self.profile)
@@ -290,17 +298,20 @@ class Trainer:
 
             batch = self._batch(step)
             with StepTimer() as t:
-                storage, opt_state, metrics = self.step_fn(
-                    storage, opt_state, batch)
+                storage, opt_state, metrics = jax.block_until_ready(
+                    self.step_fn(storage, opt_state, batch))
                 metrics = jax.tree.map(np.asarray, metrics)
             verdict = self.straggler.observe(t.dt)
             if verdict == "escalate":
                 log.warning("straggler escalation at step %d", step)
             step += 1
+            if self._fresh_fn:
+                self._compile_steps.add(step)
+                self._fresh_fn = False
             self._record_step(step, t.dt, metrics)
             if self._replan_pending:
                 storage, opt_state = self._replan(step, storage, opt_state)
-            if step % self.tcfg.log_every == 0 or step == 1:
+            if step % self.tcfg.log_every == 0 or step in (1, stop_at):
                 self.history.append(
                     {"step": step, "dt": t.dt,
                      **{k: float(v) for k, v in metrics.items()}})

@@ -64,9 +64,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-
-from repro.core.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import (BucketPlan, DistConfig, ParamMeta, apply_stack,
@@ -1262,8 +1260,7 @@ CASES["trainer_smoke_b"] = \
 #      harness's standard cross-mesh tolerance (psum reassociation makes
 #      bitwise cross-mesh equality impossible even for dense prefill),
 #      with identical greedy tokens at every step.
-# Explicit-collective design (shard_map + check_vma=False): exact on
-# jax 0.4 per the ROADMAP vma constraint.
+# Explicit-collective design (shard_map + check_vma=False).
 # --------------------------------------------------------------------------
 def case_serving():
     from repro.core.serving import pages as PG

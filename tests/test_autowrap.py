@@ -2,8 +2,8 @@
 
 Covers the exposure-minimizing DP planner (`bucket_mode="auto_dp"`), the
 guarded greedy planner, plan memoization, the segmented bucket-granular
-prefetch stack's exact parity against the vanilla stack (1 device, fp32 —
-the jax-0.4 vma gap stays out of tier-1, per ROADMAP), and the
+prefetch stack's exact parity against the vanilla stack (1 device, fp32),
+and the
 BENCH_overlap.json emission schema.
 
 Property tests use `hypothesis` when available and fall back to a fixed
@@ -216,7 +216,7 @@ def test_plan_for_auto_dp_resolves():
 
 # ---------------------------------------------------------------------------
 # Segmented bucket-granular prefetch: exact parity vs the vanilla stack
-# (1 device, fp32 — keeps the jax-0.4 vma gap out of tier-1, per ROADMAP).
+# (1 device, fp32).
 # ---------------------------------------------------------------------------
 SD_CFG = DistConfig(mesh_axes=("data", "model"), mesh_shape=(1, 1),
                     param_dtype=jnp.float32, reduce_dtype=jnp.float32)

@@ -180,7 +180,7 @@ def _cp_cfg(**kw):
 def test_dist_config_cp_properties():
     d = _cp_cfg()
     assert d.cp_size == 2 and d.dp_total == 4 and d.batch_dp == 2
-    assert DistConfig().cp_size == 1
+    assert DistConfig(mesh_shape=(1, 1)).cp_size == 1
 
 
 def test_plan_parallel_cp_validation_errors():

@@ -12,18 +12,7 @@ import sys
 
 import pytest
 
-from repro.core.compat import HAS_VMA
-
 pytestmark = pytest.mark.distributed
-
-# Cases exercising TP-replicated params consumed by TP-varying compute rely
-# on the vma replication-transpose (auto-psum of cotangents over the model
-# axis) that only the jax>=0.6 shard_map provides; on older jax they are
-# version-gated (ROADMAP "Old-jax vma parity gap"). The pipeline case stays
-# active everywhere: its cross-rank flows use explicit collectives only.
-needs_vma = pytest.mark.skipif(
-    not HAS_VMA,
-    reason="needs jax>=0.6 shard_map vma replication-transpose semantics")
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -47,33 +36,28 @@ def test_gather_reconstructs_params():
     _run("gather_values")
 
 
-@needs_vma
 def test_vanilla_stack_matches_dense():
     """scan(remat(gather->compute)) == dense reference, all mesh layouts,
     bucketed and per-param plans."""
     _run("vanilla")
 
 
-@needs_vma
 def test_remat_policies_match_dense():
     _run("remat_modes")
 
 
 @pytest.mark.slow
-@needs_vma
 def test_prefetch_stack_all_schedules():
     """The hand-scheduled double-buffered scan (paper's reorder+bucket)
     under every Table-6 flag combination x 3 mesh layouts."""
     _run("prefetch", timeout=560)
 
 
-@needs_vma
 def test_prefetch_bucket_plans():
     _run("prefetch_buckets")
 
 
 @pytest.mark.slow
-@needs_vma
 def test_all_architectures_mesh_equivalence():
     """All 10 assigned archs: (2 data x 4 model) == single device, exact
     losses and gradients (TP/SP/EP/grouped-GQA paths)."""

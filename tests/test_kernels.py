@@ -125,6 +125,24 @@ def test_adamw_sweep(n, dtype):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), **TOL32)
 
 
+@pytest.mark.parametrize("t", [1, 2, 10, 1000])
+def test_adamw_bias_correction_traced_step(t):
+    """The bias corrections 1 - b**t reach the kernel as operands computed
+    from a traced step count (the optimizer state's), not as a power inside
+    the kernel."""
+    from repro.kernels.adamw import ops, ref
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    n = 3000
+    p, g, m = (jax.random.normal(k, (n,)) for k in ks[:3])
+    v = jnp.abs(jax.random.normal(ks[3], (n,))) * 0.01
+    kw = dict(lr=1e-3, b1=0.9, b2=0.95, eps=1e-8, wd=0.1)
+    got = jax.jit(lambda t: ops.adamw_update_pallas(
+        p, g, m, v, t=t, interpret=True, **kw))(jnp.asarray(t, jnp.int32))
+    want = ref.adamw_update(p, g, m, v, t=jnp.asarray(t), **kw)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), **TOL32)
+
+
 # ---------------------------------------------------------------------------
 # SSD (Mamba-2)
 # ---------------------------------------------------------------------------

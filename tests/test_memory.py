@@ -375,3 +375,17 @@ def test_checked_in_bench_memory_json_is_current_schema():
     doc = json.load(open(path))
     assert doc["schema"] == "bench_memory_v1"
     assert len(doc["archs"]) >= 3
+
+
+def test_host_offload_round_trip():
+    """The backend exposes pinned_host (jax 0.9's CPU does too): the
+    transfer really moves the arrays, and back, with their values."""
+    tree = {"m": jnp.arange(8.0), "v": jnp.ones((2, 4))}
+    assert MEM.host_offload_supported()
+    host = MEM.to_host(tree)
+    assert all(x.sharding.memory_kind == "pinned_host"
+               for x in jax.tree.leaves(host))
+    back = MEM.to_device(host)
+    for a, b in zip(jax.tree.leaves(back), jax.tree.leaves(tree)):
+        assert a.sharding.memory_kind == "device"
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -61,6 +61,23 @@ def test_train_step_smoke(arch):
     assert np.isfinite(gsq) and gsq > 0
 
 
+def test_train_step_long_seq_chunked_attention():
+    """seq 2048 sends attention to `attention_chunked` (S*T > 1M); its
+    online-softmax scan carries must trace under shard_map's vma check."""
+    cfg, model = get_arch("qwen3_1_7b", smoke=True)
+    shape = ShapeConfig("t", 2048, 1, "train")
+    storage = RT.init_storage(model, jax.random.PRNGKey(0), DCFG)
+    batch = _batch(model, cfg, shape)
+    specs = RT.model_storage_specs(model, DCFG)
+    fn, _ = RT.wrap_step(model, DCFG, shape, RT.make_loss_step(model, DCFG),
+                         (P(), specs))
+    loss, grads = fn(storage, batch)
+    assert abs(float(loss) - np.log(cfg.vocab)) < 0.35, float(loss)
+    gsq = sum(float((g.astype(jnp.float32) ** 2).sum())
+              for _, g in named_leaves(grads))
+    assert np.isfinite(gsq) and gsq > 0
+
+
 @pytest.mark.parametrize("arch", ["qwen3_1_7b", "gemma2_27b",
                                   "qwen2_moe_a2_7b"])
 def test_prefill_decode_consistency(arch):

@@ -11,9 +11,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 
 from repro.core import DistConfig, make_mesh
-from repro.core.compat import shard_map
 from repro.core.dist import single_device_config
 from repro.core.pipeline import (gpipe, gpipe_grads, gpipe_schedule,
                                  one_f_one_b, one_f_one_b_schedule,

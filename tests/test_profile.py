@@ -280,7 +280,10 @@ def test_trainer_replan_hook_applies(tmp_path):
     assert tr.registry.counter("replan/count").value >= 1
     if delta["changed"]:
         assert delta["applied"]
-        assert tr.plan.describe() == delta["after"]
+        # the loop runs on the plan of the last replan it applied (CPU
+        # walls may re-arm the streak after the first one)
+        last = [d for d in tr.replans if d["applied"]][-1]
+        assert tr.plan.describe() == last["after"]
         # the promise was re-anchored to the calibrated model: the loop's
         # remaining steps must not arm another replan
         rows = tr.drift.records["step_time"]

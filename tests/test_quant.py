@@ -134,7 +134,7 @@ def test_quantized_adamw_tracks_bf16():
     """~50 toy AdamW steps on a least-squares problem: the fp8_ef run's
     loss trajectory tracks the unquantized run within a loose tolerance
     (EF-theory: compensated quantization preserves convergence)."""
-    from repro.core.compat import shard_map
+    from jax import shard_map
     from repro.core.dist import make_mesh
     from repro.core.meta import ParamMeta, from_storage, to_storage
     from repro.optim.adamw import AdamWConfig, apply_adamw, init_opt_state

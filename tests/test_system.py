@@ -11,7 +11,7 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
-from repro.core.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import DistConfig, make_mesh
