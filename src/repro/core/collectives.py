@@ -150,6 +150,7 @@ def _vma_classes(metas: Sequence[ParamMeta]) -> list[list[int]]:
     return list(cls.values())
 
 
+@jax.named_scope("fsdp_gather")            # core/obs.SCOPES
 def gather_group_fwd_raw(shards: Sequence[jax.Array],
                          metas: Sequence[ParamMeta],
                          cfg: DistConfig,
@@ -181,6 +182,7 @@ def rs_dtype(cfg: DistConfig):
     return jnp.bfloat16 if cfg.grad_compression else cfg.reduce_dtype
 
 
+@jax.named_scope("fsdp_reduce_scatter")    # core/obs.SCOPES
 def pack_grad_bucket(grads_full: Sequence[jax.Array],
                      metas: Sequence[ParamMeta],
                      cfg: DistConfig) -> tuple[jax.Array, ...]:
@@ -192,6 +194,7 @@ def pack_grad_bucket(grads_full: Sequence[jax.Array],
     )
 
 
+@jax.named_scope("fsdp_reduce_scatter")    # core/obs.SCOPES
 def finalize_grad_bucket(cts: tuple, metas: Sequence[ParamMeta],
                          cfg: DistConfig,
                          shard_shapes: Sequence[tuple],

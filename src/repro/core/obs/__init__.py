@@ -1,7 +1,8 @@
 """Unified telemetry: plan-aligned trace timelines (`trace`), the typed
 per-step metrics registry (`metrics`), the modeled-vs-measured drift
-monitor (`drift`), and the profile -> calibrate -> replan loop
-(`profile` + `calibrate`).
+monitor (`drift`), the profile -> calibrate -> replan loop
+(`profile` + `calibrate`), and the named parts of a step: device scopes and
+host spans (`spans`).
 
 The observability counterpart of the plan-centric architecture: every
 cost model in the repo (collective exposure, pipeline bubble, memory
@@ -19,6 +20,7 @@ from repro.core.obs.drift import SUBSYSTEMS, DriftMonitor, modeled_step_time
 from repro.core.obs.metrics import (Counter, Gauge, Histogram,
                                     MetricsRegistry, default_registry)
 from repro.core.obs.profile import MeasuredProfile, profile_step
+from repro.core.obs.spans import SCOPES, gc_span, span, span_times
 from repro.core.obs.trace import (PID_MEASURED, PID_MODELED, PID_SERVING,
                                   TID_COMM, TID_COMPUTE, TID_STRAGGLER,
                                   TraceBuilder, comm_windows,
@@ -31,6 +33,7 @@ __all__ = [
     "SUBSYSTEMS", "DriftMonitor", "modeled_step_time",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "default_registry",
     "MeasuredProfile", "profile_step",
+    "SCOPES", "gc_span", "span", "span_times",
     "calibrated_block_stats", "calibrated_step_time", "calibration",
     "replan",
     "PID_MEASURED", "PID_MODELED", "PID_SERVING", "TID_COMM", "TID_COMPUTE",

@@ -24,6 +24,7 @@ from a fresh checkpoint directory unless --ckpt-dir names one to resume.
 """
 
 import argparse
+import contextlib
 import dataclasses
 import math
 import tempfile
@@ -116,15 +117,22 @@ def main():
                     help="append a metrics-registry snapshot (step time, "
                          "tokens/s, wire bytes, drift gauges) here at "
                          "every log interval (core/obs)")
+    ap.add_argument("--xprof-dir", default=None,
+                    help="run the training loop under jax.profiler.trace "
+                         "into this directory: the measured device trace, "
+                         "with the step's named scopes on its ops and the "
+                         "loop's host spans (core/obs.spans)")
     ap.add_argument("--trace-out", default=None,
                     help="write a Chrome/Perfetto trace of the executed "
-                         "plan's modeled timeline here after the run "
-                         "(core/obs.plan_trace)")
+                         "plan's MODELED timeline here after the run "
+                         "(core/obs.plan_trace); no device measurement")
     ap.add_argument("--profile-out", default=None,
-                    help="after the run, profile the executed schedule "
-                         "(core/obs.profile_step), write the frozen "
+                    help="after the run, time the executed schedule's "
+                         "segments and collectives one by one on the host's "
+                         "wall clock (core/obs.profile_step; not a device "
+                         "trace), write the frozen "
                          "MeasuredProfile JSON here plus a modeled-vs-"
-                         "measured overlay trace next to it "
+                         "host-timed overlay trace next to it "
                          "(<profile-out>.trace.json)")
     ap.add_argument("--replan-threshold", type=float, default=None,
                     help="arm profile-guided replanning: mean |rel| "
@@ -197,7 +205,11 @@ def main():
                          replan_apply=args.replan_apply)
     trainer = Trainer(model, dcfg, shape, AdamWConfig(lr=args.lr), tcfg)
     print(f"plan: {trainer.plan.describe()}")
-    _, _, hist = trainer.run()
+    with (jax.profiler.trace(args.xprof_dir) if args.xprof_dir
+          else contextlib.nullcontext()):
+        _, _, hist = trainer.run()
+    if args.xprof_dir:
+        print(f"xprof: {args.xprof_dir}")
     print(f"done: loss {hist[0]['loss']:.4f} -> {hist[-1]['loss']:.4f}")
     if trainer.drift.records:
         print(trainer.drift.report())

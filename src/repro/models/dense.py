@@ -183,6 +183,7 @@ class DenseLM:
         o = lax.psum(o, dcfg.tp_axis)
         return o
 
+    @jax.named_scope("attn")               # core/obs.SCOPES
     def _attn_half(self, p, consts, x, dcfg, window):
         """Attention residual branch: consumes ln1 + attn.* (+pn1)."""
         cfg = self.cfg
@@ -194,6 +195,7 @@ class DenseLM:
             h = LY.rmsnorm(h, p["pn1"], cfg.norm_eps, uo)
         return x + h
 
+    @jax.named_scope("mlp")                # core/obs.SCOPES
     def _mlp_half(self, p, consts, x, dcfg):
         """FFN residual branch: consumes ln2 + mlp.* (+pn2); returns aux."""
         cfg = self.cfg
@@ -337,6 +339,7 @@ class DenseLM:
                               segments=self.block_segments(dcfg))
         return x, jax.tree.map(jnp.add, aux, aux2)
 
+    @jax.named_scope("head_loss")          # core/obs.SCOPES
     def stage_loss(self, storage, state, mb, dcfg: DistConfig):
         """Last-stage exit: final norm, LM head, vocab-parallel CE (+aux)."""
         cfg = self.cfg

@@ -245,6 +245,7 @@ def embed_init(key, cfg: ArchConfig):
     return jax.random.normal(key, (cfg.vocab, cfg.d_model)) * 0.02
 
 
+@jax.named_scope("embed")                  # core/obs.SCOPES
 def embed_apply(table_local, ids, cfg: ArchConfig, dcfg: DistConfig,
                 scale: float | None = None, scatter: bool = True):
     """table_local: (V/tp, D); ids: (B, S) -> SP (B, S/tp, D)."""

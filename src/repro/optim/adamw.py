@@ -116,6 +116,7 @@ def _error_feedback(grads, ef):
     return gq, new_ef
 
 
+@jax.named_scope("optimizer")              # core/obs.SCOPES
 def apply_adamw(storage, grads, opt_state, metas_tree, cfg: DistConfig,
                 ocfg: AdamWConfig, lr, pp_replicated: tuple[str, ...] = ()):
     """One AdamW step on the sharded storage. Returns (params, opt_state,

@@ -28,6 +28,7 @@ import numpy as np
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.core.api import parallelize
 from repro.core.dist import DistConfig
+from repro.core.obs.spans import gc_span, span
 from repro.data.pipeline import DataConfig, SyntheticC4, adapt_batch
 from repro.ft.failures import (FailureSource, StepTimer, StragglerMonitor)
 from repro.models.common import ShapeConfig
@@ -73,44 +74,56 @@ class Trainer:
                                     modeled_step_time)
         from repro.train.train_step import step_wire_metrics
 
-        self.model, self.dcfg, self.shape = model, dcfg, shape
-        self.ocfg, self.tcfg = ocfg, tcfg
-        self.failures = failure_source or FailureSource()
-        self.straggler = StragglerMonitor()
-        self.ckpt = Checkpointer(tcfg.ckpt_dir, async_save=tcfg.async_ckpt)
-        self.data = SyntheticC4(DataConfig(
-            vocab=model.cfg.vocab, seq_len=shape.seq_len,
-            global_batch=shape.global_batch, seed=seed))
-        self._seed = seed
-        sched = default_schedule(ocfg, tcfg.total_steps, tcfg.warmup)
-        self.par = parallelize(model, dcfg, shape)
-        self.plan = self.par.plan
-        self.mesh = self.par.mesh
-        self.step_fn = self.par.train_step(ocfg, sched)
-        self.history: list[dict] = []
-        self.restarts = 0
-        # profile-guided replanning state: drift streak, the latest
-        # harvested MeasuredProfile, and one delta record per replan
-        self._drift_streak = 0
-        self._replan_pending = False
-        # the first step of each step_fn compiles it: its wall is no
-        # steady-state measurement for a replan to calibrate against
-        self._fresh_fn = True
-        self._compile_steps: set[int] = set()
-        self.profile = None
-        self.replans: list[dict] = []
         # observability: one registry + drift monitor per trainer; the
         # plan's own step-time promise and per-step wire bytes are frozen
-        # up front so the run loop only records measurements
+        # up front so the run loop only records measurements. The registry
+        # comes first: it holds the set-up's own spans (core/obs.spans).
         self.registry = registry if registry is not None \
             else MetricsRegistry()
-        self.drift = DriftMonitor(self.registry)
-        self._modeled_step_s = modeled_step_time(model, self.plan, shape)
-        self._wire = step_wire_metrics(model, self.plan)
-        if self.plan.memory is not None:
-            log.info("plan: %s", self.plan.describe())
-            for b in self.plan.memory.breakdown:
-                log.info("modeled peak %s", b.describe())
+        with self._span("trainer_init"):
+            self.model, self.dcfg, self.shape = model, dcfg, shape
+            self.ocfg, self.tcfg = ocfg, tcfg
+            self.failures = failure_source or FailureSource()
+            self.straggler = StragglerMonitor()
+            with self._span("checkpointer"):
+                self.ckpt = Checkpointer(tcfg.ckpt_dir,
+                                         async_save=tcfg.async_ckpt)
+            with self._span("data"):
+                self.data = SyntheticC4(DataConfig(
+                    vocab=model.cfg.vocab, seq_len=shape.seq_len,
+                    global_batch=shape.global_batch, seed=seed))
+            self._seed = seed
+            sched = default_schedule(ocfg, tcfg.total_steps, tcfg.warmup)
+            with self._span("parallelize"):
+                self.par = parallelize(model, dcfg, shape)
+            self.plan = self.par.plan
+            self.mesh = self.par.mesh
+            with self._span("train_step"):
+                self.step_fn = self.par.train_step(ocfg, sched)
+            self.history: list[dict] = []
+            self.restarts = 0
+            # profile-guided replanning state: drift streak, the latest
+            # harvested MeasuredProfile, and one delta record per replan
+            self._drift_streak = 0
+            self._replan_pending = False
+            # the first step of each step_fn compiles it: its wall is no
+            # steady-state measurement for a replan to calibrate against
+            self._fresh_fn = True
+            self._compile_steps: set[int] = set()
+            self.profile = None
+            self.replans: list[dict] = []
+            self.drift = DriftMonitor(self.registry)
+            with self._span("cost_model"):
+                self._modeled_step_s = modeled_step_time(model, self.plan,
+                                                         shape)
+                self._wire = step_wire_metrics(model, self.plan)
+            if self.plan.memory is not None:
+                log.info("plan: %s", self.plan.describe())
+                for b in self.plan.memory.breakdown:
+                    log.info("modeled peak %s", b.describe())
+
+    def _span(self, name: str) -> span:
+        return span(name, self.registry)
 
     def memory_report(self, measured: bool = True) -> dict:
         """Modeled (live-range simulator, core/memory) vs measured (XLA
@@ -282,8 +295,16 @@ class Trainer:
         return storage, opt_state
 
     def run(self, key=None):
+        """The training loop. Its parts are host spans (core/obs.spans):
+        restore, batch, step (the dispatch), sync, record, replan and
+        checkpoint, with every Python garbage collection a `gc` span."""
+        with gc_span(self.registry):
+            return self._run(key)
+
+    def _run(self, key):
         key = key if key is not None else jax.random.PRNGKey(0)
-        storage, opt_state, start = self._init_or_restore(key)
+        with self._span("restore"):
+            storage, opt_state, start = self._init_or_restore(key)
         step = start
         stop_at = self.tcfg.stop_after or self.tcfg.total_steps
         while step < stop_at:
@@ -292,15 +313,19 @@ class Trainer:
                 if self.restarts > self.tcfg.max_restarts:
                     raise RuntimeError("restart budget exhausted")
                 log.warning("failure detected at step %d; restarting", step)
-                self.ckpt.wait()
-                storage, opt_state, step = self._init_or_restore(key)
+                with self._span("restore"):
+                    self.ckpt.wait()
+                    storage, opt_state, step = self._init_or_restore(key)
                 continue
 
-            batch = self._batch(step)
+            with self._span("batch"):
+                batch = self._batch(step)
             with StepTimer() as t:
-                storage, opt_state, metrics = jax.block_until_ready(
-                    self.step_fn(storage, opt_state, batch))
-                metrics = jax.tree.map(np.asarray, metrics)
+                with self._span("step"):
+                    out = self.step_fn(storage, opt_state, batch)
+                with self._span("sync"):
+                    storage, opt_state, metrics = jax.block_until_ready(out)
+                    metrics = jax.tree.map(np.asarray, metrics)
             verdict = self.straggler.observe(t.dt)
             if verdict == "escalate":
                 log.warning("straggler escalation at step %d", step)
@@ -308,21 +333,28 @@ class Trainer:
             if self._fresh_fn:
                 self._compile_steps.add(step)
                 self._fresh_fn = False
-            self._record_step(step, t.dt, metrics)
+            with self._span("record"):
+                self._record_step(step, t.dt, metrics)
             if self._replan_pending:
-                storage, opt_state = self._replan(step, storage, opt_state)
+                with self._span("replan"):
+                    storage, opt_state = self._replan(step, storage,
+                                                      opt_state)
             if step % self.tcfg.log_every == 0 or step in (1, stop_at):
-                self.history.append(
-                    {"step": step, "dt": t.dt,
-                     **{k: float(v) for k, v in metrics.items()}})
-                log.info("step %d loss %.4f gnorm %.3f %.0fms", step,
-                         metrics["loss"], metrics["grad_norm"],
-                         t.dt * 1e3)
-                if self.tcfg.metrics_jsonl:
-                    self.registry.dump_jsonl(self.tcfg.metrics_jsonl,
-                                             step=step)
+                with self._span("record"):
+                    self._log_step(step, t.dt, metrics)
             if step % self.tcfg.ckpt_every == 0 \
                     or step in (self.tcfg.total_steps, stop_at):
-                self._save(step, storage, opt_state)
-        self.ckpt.wait()
+                with self._span("checkpoint"):
+                    self._save(step, storage, opt_state)
+        with self._span("checkpoint"):
+            self.ckpt.wait()
         return storage, opt_state, self.history
+
+    def _log_step(self, step: int, dt: float, metrics) -> None:
+        self.history.append(
+            {"step": step, "dt": dt,
+             **{k: float(v) for k, v in metrics.items()}})
+        log.info("step %d loss %.4f gnorm %.3f %.0fms", step,
+                 metrics["loss"], metrics["grad_norm"], dt * 1e3)
+        if self.tcfg.metrics_jsonl:
+            self.registry.dump_jsonl(self.tcfg.metrics_jsonl, step=step)
