@@ -86,10 +86,11 @@ def global_grad_norm(grads_tree, metas_tree, cfg: DistConfig,
     return jnp.sqrt(total)
 
 
-def _update_leaf(p, g, m, v, lr, ocfg: AdamWConfig, t):
+def _update_leaf(p, g, m, v, lr, ocfg: AdamWConfig, t, scale):
     from repro.kernels.adamw import ops as adamw_ops
     return adamw_ops.adamw_update(p, g, m, v, lr=lr, b1=ocfg.b1, b2=ocfg.b2,
-                                  eps=ocfg.eps, wd=ocfg.weight_decay, t=t)
+                                  eps=ocfg.eps, wd=ocfg.weight_decay, t=t,
+                                  scale=scale)
 
 
 def _error_feedback(grads, ef):
@@ -130,8 +131,8 @@ def apply_adamw(storage, grads, opt_state, metas_tree, cfg: DistConfig,
         if ocfg.grad_clip else 1.0
 
     def upd(p, g, m, v):
-        g = g.astype(jnp.float32) * scale
-        return _update_leaf(p, g, m, v, lr, ocfg, t)
+        # the clip scale is applied to the f32 gradient inside the update
+        return _update_leaf(p, g, m, v, lr, ocfg, t, scale)
 
     out = jax.tree.map(upd, storage, grads, opt_state["m"], opt_state["v"])
     new_p = jax.tree.map(lambda o: o[0], out,

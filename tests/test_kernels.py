@@ -1,5 +1,8 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs pure-jnp oracle."""
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -109,20 +112,57 @@ def test_xent_grad():
 # ---------------------------------------------------------------------------
 # AdamW
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("n", [1024, 5000])
-@pytest.mark.parametrize("dtype", [jnp.float32])
-def test_adamw_sweep(n, dtype):
-    from repro.kernels.adamw import ops, ref
-    ks = jax.random.split(jax.random.PRNGKey(0), 4)
-    p = jax.random.normal(ks[0], (n,)).astype(dtype)
-    g = jax.random.normal(ks[1], (n,)).astype(dtype)
-    m = jax.random.normal(ks[2], (n,)).astype(dtype) * 0.1
-    v = jnp.abs(jax.random.normal(ks[3], (n,))).astype(dtype) * 0.01
-    kw = dict(lr=1e-3, b1=0.9, b2=0.95, eps=1e-8, wd=0.1, t=jnp.asarray(3))
-    got = ops.adamw_update_pallas(p, g, m, v, interpret=True, **kw)
-    want = ref.adamw_update(p, g, m, v, **kw)
+ADAMW_KW = dict(lr=1e-3, b1=0.9, b2=0.95, eps=1e-8, wd=0.1)
+# storage leaves are (chunk,), (tp, chunk), (L, chunk) or (L, tp, chunk)
+# with chunk a multiple of 128 (core/meta.py); the rest are any other leaf
+ADAMW_LEAVES = [
+    pytest.param(1024, 1.0, id="1024"),
+    pytest.param(5000, 1.0, id="5000"),
+    pytest.param((1, 2048), 0.37, id="tp-chunk"),
+    pytest.param((2, 1024), 0.37, id="tp2-chunk"),
+    pytest.param((3, 1280), 0.37, id="L-chunk"),
+    pytest.param((3, 1, 768), 0.37, id="L-tp-chunk"),
+    pytest.param((2, 3, 256), 0.37, id="L-tp3-chunk"),
+    pytest.param((3,), 0.37, id="under-one-vreg"),
+    pytest.param((3, 100), 0.37, id="not-lane-dense"),
+    # more than one block, the last one partial: a linear leaf splits its
+    # rows, a stacked one its columns
+    pytest.param((1, 600_064), 0.37, id="tp-chunk-blocks"),
+    pytest.param((7, 100_352), 0.37, id="L-chunk-blocks"),
+]
+
+
+def _adamw_state(shape, dtype, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    p = jax.random.normal(ks[0], shape).astype(dtype)
+    g = jax.random.normal(ks[1], shape).astype(dtype)
+    m = (jax.random.normal(ks[2], shape) * 0.1).astype(dtype)
+    v = (jnp.abs(jax.random.normal(ks[3], shape)) * 0.01).astype(dtype)
+    return p, g, m, v
+
+
+def _close(got, want, dtype):
+    tol = dict(rtol=1e-6, atol=1e-7) if dtype == jnp.float32 else TOL
     for a, b in zip(got, want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), **TOL32)
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), **tol)
+
+
+@pytest.mark.parametrize("shape,scale", ADAMW_LEAVES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_adamw_sweep(shape, scale, dtype):
+    """The kernel against the oracle given the clip-scaled gradient, over
+    every storage rank, leaves under one block, past one block and not
+    lane-dense, f32 and bf16 params."""
+    from repro.kernels.adamw import ops, ref
+    p, g, m, v = _adamw_state(shape, dtype)
+    kw = dict(ADAMW_KW, t=jnp.asarray(3))
+    got = ops.adamw_update_pallas(p, g, m, v, scale=scale, interpret=True,
+                                  **kw)
+    want = ref.adamw_update(p, g.astype(jnp.float32) * scale, m, v, **kw)
+    _close(got, want, dtype)
+
 
 
 @pytest.mark.parametrize("t", [1, 2, 10, 1000])
@@ -141,6 +181,132 @@ def test_adamw_bias_correction_traced_step(t):
     want = ref.adamw_update(p, g, m, v, t=jnp.asarray(t), **kw)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), **TOL32)
+
+
+def test_adamw_block_plan():
+    """The plan views each storage leaf as its own bytes (no padding), moves
+    at most BLOCK_BYTES of f32 per operand a grid step in blocks the TPU
+    can tile, covers the view exactly, and fits the VMEM limit it sets.
+    The view keeps the last two dims of a leaf the TPU tiles there."""
+    from repro.kernels.adamw import kernel as K
+
+    views = {
+        (311164928,): (1, 2430976, 128),       # the tied embedding's chunk
+        (1, 311164928): (1, 2430976, 128),     # ... behind a tp axis of 1
+        (2, 155582464): (1, 2, 155582464),     # ... behind a tp axis of 2
+        (7, 1, 12582912): (1, 688128, 128),    # a stacked TP matrix
+        (7, 2, 6291456): (7, 2, 6291456),      # ... with tp 2
+        (7, 2097152): (1, 7, 2097152),         # a stacked matrix
+        (4, 2, 1024): (4, 2, 1024),            # stacked, tp 2
+        (7, 128): (1, 7, 128),                 # stacked qk-norm scales
+        (2048,): (1, 16, 128),
+        (5000,): (1, 40, 128),                 # not a storage leaf: padded
+    }
+    elems = K.BLOCK_BYTES // 4
+    for shape, view in views.items():
+        for dtype in (jnp.float32, jnp.bfloat16):
+            plan = K.block_plan(shape, dtype)
+            assert plan.view == view, shape
+            assert plan.pad == (40 * 128 - 5000 if shape == (5000,) else 0)
+            (k, r, c), (br, bc) = plan.view, plan.block
+            assert br % K.SUBLANES == 0 or br == r, shape
+            assert bc % K.LANES == 0 or bc == c, shape
+            assert -(-br // K.SUBLANES) * K.SUBLANES * bc <= elems, shape
+            assert plan.grid == (k, -(-r // br), -(-c // bc)), shape
+            assert plan.vmem_bytes <= plan.vmem_limit <= 64 << 20, shape
+    # the cells' large leaves: each grid step moves at least 7/8 of a block
+    # (a 7-row block is 8 rows in VMEM), where 1024-element blocks took
+    # 303,872 steps (embedding) and 86,016 (stacked)
+    for shape, steps in [((1, 311164928), 2374), ((7, 1, 12582912), 672),
+                         ((7, 2097152), 128)]:
+        plan = K.block_plan(shape, jnp.float32)
+        assert plan.steps == steps, shape
+        assert 8 * 4 * math.prod(plan.block) >= 7 * K.BLOCK_BYTES, shape
+    # a leaf under one block is one grid step over the whole leaf
+    small = K.block_plan((3, 1, 768), jnp.float32)
+    assert small.steps == 1 and small.view == (1, *small.block) == (
+        1, 18, 128)
+
+
+def test_adamw_tail_not_written():
+    """Where the plan pads a leaf or leaves a partial last block, each
+    result holds exactly the leaf's elements, every one updated as the
+    oracle updates it: no padding lands in the leaf, none is dropped."""
+    from repro.kernels.adamw import kernel as K
+    from repro.kernels.adamw import ops, ref
+
+    kw = dict(ADAMW_KW, t=jnp.asarray(2))
+    for shape in [(5000,), (3, 100), (1, 600_064), (7, 100_352)]:
+        plan = K.block_plan(shape, jnp.float32)
+        partial = any(v % b for v, b in zip(plan.view[1:], plan.block))
+        assert plan.pad or partial, shape
+        p, g, m, v = _adamw_state(shape, jnp.float32, seed=5)
+        got = jax.jit(lambda p, g, m, v: ops.adamw_update_pallas(
+            p, g, m, v, interpret=True, **kw))(p, g, m, v)
+        want = ref.adamw_update(p, g, m, v, **kw)
+        _close(got, want, jnp.float32)
+        for new, ora, old in zip(got, want, (p, m, v)):
+            # every element the oracle moves moved: none was skipped
+            moved = np.asarray(ora) != np.asarray(old)
+            assert moved.mean() > 0.99, shape
+            assert np.all(np.asarray(new)[moved] != np.asarray(old)[moved])
+        # the padded view's tail (zeros in, zeros out) never reaches a result
+        if plan.pad:
+            assert got[0].size == p.size
+
+
+def test_apply_adamw_unchanged(monkeypatch):
+    """`apply_adamw` through the kernel, with the clip scale now applied
+    inside it, gives the params and moments that scaling the gradient
+    first and then running the oracle gives, on a tree of every storage
+    rank with a gradient large enough to clip."""
+    from jax import shard_map
+    from repro.core.dist import DistConfig, make_mesh
+    from repro.core.meta import ParamMeta
+    from repro.kernels.adamw import ops, ref
+    from repro.optim import adamw as A
+
+    cfg = DistConfig(mesh_axes=("data", "model"), mesh_shape=(1, 1),
+                     fsdp_axes=("data",), param_dtype=jnp.float32,
+                     reduce_dtype=jnp.float32)
+    shapes = {"a": (1024,), "b": (1, 768), "c": (3, 512), "d": (2, 1, 384)}
+    metas = {k: ParamMeta(k, s, tp_dim=1 if k in "bd" else None)
+             for k, s in shapes.items()}
+    storage = {k: _adamw_state(s, jnp.float32, seed=i)[0]
+               for i, (k, s) in enumerate(shapes.items())}
+    grads = {k: 5 * _adamw_state(s, jnp.float32, seed=i + 9)[1]
+             for i, (k, s) in enumerate(shapes.items())}
+    opt = A.init_opt_state(storage)
+    opt["m"] = jax.tree.map(lambda x: 0.1 * x, grads)
+    opt["v"] = jax.tree.map(lambda x: 0.01 * x * x, grads)
+    opt["step"] = jnp.asarray(4, jnp.int32)
+    ocfg = A.AdamWConfig(lr=1e-3)
+    P = jax.sharding.PartitionSpec
+    mesh = make_mesh(cfg)
+
+    def run(st, g, o):
+        return A.apply_adamw(st, g, o, metas, cfg, ocfg, ocfg.lr)
+
+    # the TPU branch, with the kernel interpreted
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ops, "adamw_update_pallas", functools.partial(
+        ops.adamw_update_pallas, interpret=True))
+    new_p, new_opt, gnorm = jax.jit(shard_map(
+        run, mesh=mesh, in_specs=P(), out_specs=P(),
+        check_vma=False))(storage, grads, opt)
+    monkeypatch.undo()
+    scale = ocfg.grad_clip / float(gnorm)
+    assert scale < 0.5
+    t = jnp.asarray(5, jnp.int32)
+    for k in shapes:
+        want = ref.adamw_update(storage[k], grads[k] * scale, opt["m"][k],
+                                opt["v"][k], lr=ocfg.lr, b1=ocfg.b1,
+                                b2=ocfg.b2, eps=ocfg.eps,
+                                wd=ocfg.weight_decay, t=t)
+        got = (new_p[k], new_opt["m"][k], new_opt["v"][k])
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-7)
 
 
 # ---------------------------------------------------------------------------

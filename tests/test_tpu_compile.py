@@ -11,7 +11,9 @@ only one process may load the TPU library at a time, and this file's tests
 stay together on the worker that loads it.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +23,7 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.adamw.kernel import BLOCK_BYTES, block_plan
 from repro.kernels.adamw.ops import adamw_update_pallas
 from repro.kernels.quant.ops import roundtrip_pallas
 from repro.kernels.rmsnorm.ops import rmsnorm_pallas
@@ -67,13 +70,61 @@ def test_rmsnorm_compiles(one_chip, shape):
     _compiled_text(lambda x, w: rmsnorm_pallas(x, w, 1e-6, False), x, w)
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
-                         ids=["bf16", "f32"])
-def test_adamw_compiles(one_chip, dtype):
-    p = jax.ShapeDtypeStruct((2048, 6144), dtype, sharding=one_chip)
+def _leaf_ops(text: str, n: int) -> list[str]:
+    """The ops of the compiled entry computation whose result (or first
+    result, for a tuple) has n elements."""
+    entry = text[text.index("\nENTRY"):]
+    ops = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([\w-]+)\(", line)
+        dims = m and re.match(r"\(?\w+\[([\d,]*)\]", m.group(1))
+        if dims and dims.group(1) and math.prod(
+                int(d) for d in dims.group(1).split(",")) == n:
+            ops.append(m.group(2))
+    return ops
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    pytest.param((2048, 6144), jnp.bfloat16, id="bf16"),
+    pytest.param((2048, 6144), jnp.float32, id="f32"),
+    # the cells' largest leaves: the tied embedding and a stacked matrix,
+    # with and without their tp axis of 1
+    pytest.param((311164928,), jnp.float32, id="embed-f32"),
+    pytest.param((1, 311164928), jnp.float32, id="embed-tp-f32"),
+    pytest.param((7, 12582912), jnp.float32, id="stacked-f32"),
+    pytest.param((7, 1, 12582912), jnp.float32, id="stacked-tp-f32"),
+    # the same leaves behind a tp axis of 2, as a TP-sharded leaf is stored
+    # before shard_map hands each device its (1, chunk) row
+    pytest.param((2, 155582464), jnp.float32, id="embed-tp2-f32"),
+    pytest.param((7, 2, 6291456), jnp.bfloat16, id="stacked-tp2-bf16"),
+])
+def test_adamw_compiles(one_chip, shape, dtype):
+    """The kernel compiles at the leaf's shape with grid steps that move at
+    least half a block of the rows the view has (a view of fewer than 8
+    rows fills only that share of a block), within the VMEM limit its plan
+    sets, updates p, m and v in place, and the leaf reaches and leaves it
+    through bitcasts alone."""
+    plan = block_plan(shape, dtype)
+    rows = min(plan.view[1], 8)
+    assert plan.steps <= 2 * 4 * math.prod(shape) * 8 // (
+        rows * BLOCK_BYTES) + 1
+    assert plan.vmem_bytes <= plan.vmem_limit
+    p = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    g = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
     t = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    _compiled_text(lambda p, g, m, v, t: adamw_update_pallas(
-        p, g, m, v, t=t, **ADAMW), p, p, p, p, t)
+    s = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    # the step donates the train state, as here
+    text = jax.jit(lambda p, g, m, v, t, s: adamw_update_pallas(
+        p, g, m, v, t=t, scale=s, **ADAMW), donate_argnums=(0, 2, 3)).lower(
+        p, g, p, p, t, s).compile().as_text()
+    call = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(call) == 1
+    assert "output_to_operand_aliasing={{0}: (1, {}), {1}: (3, {}), " \
+        "{2}: (4, {})}" in call[0]
+    ops = set(_leaf_ops(text, math.prod(shape)))
+    assert "custom-call" in ops
+    assert ops <= {"parameter", "bitcast", "custom-call",
+                   "get-tuple-element", "tuple"}, ops
 
 
 @pytest.mark.parametrize("codec", ["fp8", "int8"])
